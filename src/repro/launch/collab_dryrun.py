@@ -52,8 +52,8 @@ from repro.launch.mesh import make_production_mesh
 from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state
 from repro.sharding.specs import (CLIENT_AXIS, client_opt_specs,
                                   client_stacked_specs, cohort_uid_spec,
-                                  mesh_batch_axes, sample_plan_specs,
-                                  sanitize_spec)
+                                  make_mesh, mesh_batch_axes,
+                                  sample_plan_specs, sanitize_spec)
 
 
 def main():
@@ -115,7 +115,7 @@ def main():
             "vmapped per-client convs as grouped convolutions whose feature "
             "dim interleaves clients x channels, so the sharded client count "
             "must tile the channel blocks (powers of two here).")
-    cmesh = jax.make_mesh((k, n_dev // k), (CLIENT_AXIS, "data"))
+    cmesh = make_mesh((k, n_dev // k), (CLIENT_AXIS, "data"))
     csh = lambda s, spec: jax.ShapeDtypeStruct(
         s.shape, s.dtype, sharding=jax.sharding.NamedSharding(
             cmesh, sanitize_spec(spec, s.shape, cmesh)))
@@ -201,8 +201,6 @@ def main():
         with fmesh:
             compiled = jax.jit(fn).lower(*fargs).compile()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, list):  # older jax: one dict per device
-            cost = cost[0] if cost else {}
         census = collective_census(compiled.as_text())
         mem = compiled.memory_analysis()
         results[name] = {

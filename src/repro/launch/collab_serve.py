@@ -23,7 +23,8 @@ per-pass reports.  ``--compare`` additionally runs the same traffic
 through a PR-3-equivalent runtime (fifo scheduler, cache off) and prints
 the speedup and the physical server-model-call reduction.  ``--toy``
 (default) uses the protocol-scale linear denoiser so the CI smoke stays
-seconds-cheap on CPU; ``--unet`` swaps in the reduced paper U-Net.
+seconds-cheap on CPU; ``--unet`` swaps in the U-Net, reduced by default
+and at the paper's published width with ``--unet-config paper``.
 
 ``--smoke`` is the CI tier-1 entry (scripts/ci.sh): a mixed-cut queue
 with repeated (y, t_ζ) traffic, served for three passes (cold fill /
@@ -51,7 +52,6 @@ children.  Outside the smoke, ``--obs-jsonl``/``--trace-out``/
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import tempfile
@@ -61,25 +61,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.ddpm_unet import SMALL
+from repro.core.collab import CollabConfig, build_denoiser, stack_clients
 from repro.core.sample_plan import SampleRequest
 from repro.core.schedules import DiffusionSchedule
-from repro.core.unet import init_unet, unet_apply
+from repro.launch.common import (UNET_CONFIGS, add_unet_config_arg,
+                                 apply_unet_config, enable_compile_cache)
 from repro.obs import ObsConfig
 from repro.serve import ServeConfig, ServeRuntime
 
 
 def build_models(args, key):
     """Returns (server_params, stacked_client_params, apply_fn)."""
-    if args.unet:
-        ucfg = dataclasses.replace(
-            SMALL, image_size=args.image_size, channels=3,
-            n_classes=args.n_classes)
+    if args.denoiser == "unet":
+        init_one, apply_fn = build_denoiser(key, CollabConfig(
+            n_clients=args.clients, image_size=args.image_size,
+            n_classes=args.n_classes,
+            unet=UNET_CONFIGS[args.unet_config]))
         ks, *kc = jax.random.split(key, args.clients + 1)
-        sp = init_unet(ks, ucfg)
-        cp = jax.tree.map(lambda *xs: jnp.stack(xs),
-                          *[init_unet(k, ucfg) for k in kc])
-        return sp, cp, lambda p, x, t, y: unet_apply(p, x, t, y, ucfg)
+        return (init_one(ks), stack_clients([init_one(k) for k in kc]),
+                apply_fn)
     sp = {"a": jnp.float32(0.2), "b": jnp.float32(0.0)}
     cp = {"a": jnp.linspace(0.1, 0.5, args.clients),
           "b": jnp.zeros((args.clients,))}
@@ -319,7 +319,7 @@ def smoke(args, queue, sp, cp, apply_fn, sched, key) -> dict:
     return steady
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=3)
     ap.add_argument("--requests", type=int, default=12)
@@ -356,8 +356,10 @@ def main(argv=None):
                          "the steady state: warm cache, no recompiles)")
     ap.add_argument("--image-size", type=int, default=8)
     ap.add_argument("--n-classes", type=int, default=4)
-    ap.add_argument("--unet", action="store_true",
-                    help="reduced paper U-Net instead of the toy denoiser")
+    ap.add_argument("--unet", dest="denoiser", action="store_const",
+                    const="unet", default="toy",
+                    help="the U-Net denoiser (--unet-config picks its "
+                         "preset) instead of the toy denoiser")
     ap.add_argument("--compare", action="store_true",
                     help="also run the PR-3-equivalent fifo/no-cache "
                          "runtime on the same traffic")
@@ -382,7 +384,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="CI preset: assert the serve-subsystem contract "
                          "(see module docstring)")
+    add_unet_config_arg(ap)
     args = ap.parse_args(argv)
+    apply_unet_config(args)
     if args.continuous:
         args.policy = "continuous"
     if args.requests < 1 or args.max_wave < 1 or args.clients < 1 \
@@ -395,9 +399,14 @@ def main(argv=None):
         # enough that every bucket sees repeats, small enough for CI
         args.requests, args.T, args.max_wave = 12, 20, 4
         args.clients, args.n_classes, args.zipf = 3, 2, 0.0
-        args.unet, args.no_cache, args.stride = False, False, 1
+        args.denoiser, args.no_cache, args.stride = "toy", False, 1
         args.sequential, args.straggle_s = False, 0.0
+    return args
 
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
     if args.t_cuts:
         cuts = [int(c) for c in args.t_cuts.split(",")]
         if len(cuts) != args.clients:

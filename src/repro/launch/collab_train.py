@@ -25,7 +25,8 @@ restores the checkpoint and continues toward ``--rounds`` total rounds —
 bitwise-equal to never having stopped, since all randomness is
 addressed by (base key, stream tag, round, uid).  ``--toy`` (default
 for --smoke) uses the protocol-scale linear denoiser; ``--denoiser
-unet`` (the default otherwise) trains the reduced paper U-Net.
+unet`` (the default otherwise) trains the U-Net, reduced by default and
+at the paper's published width with ``--unet-config paper``.
 
 ``--lag-p``/``--lag-max`` inject stragglers (addressed TAG_LAG draws),
 ``--lag-s`` charges them simulated wall-clock, and ``--async`` switches
@@ -74,6 +75,8 @@ import numpy as np
 
 from repro.core.collab import CollabConfig, build_denoiser
 from repro.data.synthetic import SyntheticConfig, make_client_datasets
+from repro.launch.common import (UNET_CONFIGS, add_unet_config_arg,
+                                 apply_unet_config, enable_compile_cache)
 from repro.obs import ObsConfig
 from repro.sharding.specs import make_client_mesh
 from repro.train import (ParticipationConfig, PrivacyConfig, TrainConfig,
@@ -99,7 +102,8 @@ def build_model(args, key):
         return init_one, lambda p, x, t, y: x * p["a"] + p["b"]
     ccfg = CollabConfig(n_clients=args.clients, T=args.T, t_cut=args.t_cut,
                         denoiser=args.denoiser, image_size=args.image_size,
-                        batch_size=args.batch, n_classes=args.n_classes)
+                        batch_size=args.batch, n_classes=args.n_classes,
+                        unet=UNET_CONFIGS[args.unet_config])
     return build_denoiser(key, ccfg)
 
 
@@ -137,10 +141,13 @@ def make_mesh(args):
     return make_client_mesh(participation_tier(args.clients))
 
 
-def fresh_runtime(args, key, init_one, apply_fn, data,
-                  obs=None) -> TrainRuntime:
+def fresh_runtime(args, key, init_one, apply_fn, data, obs=None,
+                  mesh=None) -> TrainRuntime:
+    """A runtime with every client of ``data`` registered, on ``mesh``
+    (default: ``make_mesh(args)``)."""
     rt = TrainRuntime(make_train_config(args), init_one, apply_fn, key,
-                      mesh=make_mesh(args), obs=obs)
+                      mesh=make_mesh(args) if mesh is None else mesh,
+                      obs=obs)
     for (x, y) in data:
         rt.register_client(x, y)
     return rt
@@ -367,7 +374,7 @@ def smoke(args) -> dict:
     return last
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=5)
     ap.add_argument("--T", type=int, default=1000)
@@ -459,7 +466,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="CI preset: assert the train-runtime contract "
                          "(see module docstring)")
+    add_unet_config_arg(ap)
     args = ap.parse_args(argv)
+    apply_unet_config(args)
     if args.smoke:
         # 5 ragged clients, bernoulli cohorts with mid-round dropout,
         # FedAvg + EMA on, toy denoiser — wide enough to hit >=2 tiers
@@ -477,6 +486,13 @@ def main(argv=None):
         args.async_mode = False
         args.dp_clip, args.dp_sigma, args.dp_delta = math.inf, 0.0, 1e-5
         args.secagg = False
+    return args
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    if args.smoke:
         return smoke(args)
 
     key = jax.random.PRNGKey(args.seed)

@@ -8,18 +8,18 @@ axis is also the server/client tier split (DESIGN.md §4).
 """
 from __future__ import annotations
 
-import jax
+from repro.sharding.specs import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many real devices exist (CPU tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline; see EXPERIMENTS §Roofline)

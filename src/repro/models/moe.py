@@ -25,18 +25,6 @@ from repro.configs.base import ArchConfig
 from repro.models.layers import dense_init
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` exists only from jax 0.6 (and renamed the replication
-    check to ``check_vma``); older jax ships it as
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
-
-
 def moe_init(key, cfg: ArchConfig, dtype):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     kr, kg, ku, kd = jax.random.split(key, 4)
@@ -172,7 +160,7 @@ def moe_ep(params, x, cfg: ArchConfig, mesh, batch_axes, model_axis="model"):
         # over batch shards happens in the loss reduction.
         return y.reshape(B, S, D).astype(x_loc.dtype), aux[None]
 
-    f = _shard_map(
+    f = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(), P(model_axis, None, None),
@@ -240,7 +228,7 @@ def moe_ep2d(params, x, cfg: ArchConfig, mesh, batch_axes,
         y = jax.lax.dynamic_slice_in_dim(y_all, shard * n_loc, n_loc, axis=0)
         return y.reshape(B, S, D).astype(x_loc.dtype), aux[None]
 
-    f = _shard_map(
+    f = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(),

@@ -189,21 +189,7 @@ _SERVE_REPORT_SCHEMA = {
 
 def _key_fingerprint(key) -> bytes:
     """Stable bytes of a PRNG key (raw uint32 or typed), for cache keys."""
-    try:
-        data = jax.random.key_data(key)
-    except TypeError:          # raw uint32 key on older jax
-        data = key
-    return np.asarray(data).tobytes()
-
-
-def _is_ready(x) -> bool:
-    """Non-blocking readiness probe; conservatively False when the array
-    type predates jax.Array.is_ready (latency then degrades to the old
-    retire-time gauge — an overestimate, never an underestimate)."""
-    try:
-        return bool(x.is_ready())
-    except AttributeError:
-        return False
+    return np.asarray(jax.random.key_data(key)).tobytes()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -560,7 +546,7 @@ class ServeRuntime:
     def _reap(self) -> None:
         """Retire every in-flight wave whose result is observably ready
         (oldest first; retirement order is FIFO regardless of probing)."""
-        while self._inflight and _is_ready(self._inflight[0][0]):
+        while self._inflight and self._inflight[0][0].is_ready():
             self._retire(block=True)       # ready ⇒ returns immediately
 
     def _retire(self, block: bool = True) -> bool:
@@ -570,7 +556,7 @@ class ServeRuntime:
         and the result is not ready (or nothing is in flight)."""
         if not self._inflight:
             return False
-        if not block and not _is_ready(self._inflight[0][0]):
+        if not block and not self._inflight[0][0].is_ready():
             return False
         out, tickets, wspan = self._inflight.popleft()
         tr = self._obs.tracer

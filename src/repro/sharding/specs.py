@@ -32,7 +32,6 @@ from __future__ import annotations
 from typing import Any, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 STACKED_PREFIXES = ("layers", "mamba", "enc_layers", "dec_layers")
@@ -209,13 +208,43 @@ def shard_cohort_round(mesh, xs, ys, mask, uids):
     return xs, ys, mask, uids
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """The one constructor of every mesh in the repo.  Axis types are set
+    explicitly to ``Auto``: the installed jax defaults ``jax.make_mesh`` to
+    ``Explicit`` axes, under which jit refuses the placement-by-propagation
+    these specs rely on (a vmapped client axis against a sharded operand).
+    A mesh smaller than the host takes the first devices."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_client_mesh(n_clients: int):
     """1-D ``clients`` mesh over the most local devices that evenly divide
     n_clients (1 device on a plain CPU host — specs still apply, making the
     layout portable to real multi-device runs unchanged)."""
     n_dev = len(jax.devices())
     use = max(d for d in range(1, n_dev + 1) if n_clients % d == 0)
-    return jax.make_mesh((use,), (CLIENT_AXIS,))
+    return make_mesh((use,), (CLIENT_AXIS,))
+
+
+def shard_client_stack(mesh, client_params, client_opt):
+    """Place a client-stacked (params, AdamW state) pair on ``mesh``: every
+    leaf's leading client axis over ``clients`` (client_stacked_specs /
+    client_opt_specs), so each device holds its own clients' nets."""
+    put = lambda tree, spec_tree: jax.tree.map(
+        lambda x, s: jax.device_put(
+            x, NamedSharding(mesh, sanitize_spec(s, x.shape, mesh))),
+        tree, spec_tree)
+    copt_specs = client_opt_specs(client_params)
+    return (put(client_params, client_stacked_specs(client_params)),
+            {k: put(client_opt[k], copt_specs[k])
+             for k in ("m", "v", "step")})
+
+
+def replicate(mesh, tree):
+    """Every leaf of ``tree`` whole on every device of ``mesh``."""
+    return jax.tree.map(
+        lambda x: jax.device_put(x, NamedSharding(mesh, P())), tree)
 
 
 def shard_vectorized_state(state, mesh):
@@ -223,28 +252,10 @@ def shard_vectorized_state(state, mesh):
     the ``clients`` axis, server model/opt replicated. jit then follows the
     input shardings — the vectorized round needs no collectives except the
     psum implied by the shared server update."""
-    put = lambda tree, spec_tree: jax.tree.map(
-        lambda x, s: jax.device_put(
-            x, NamedSharding(mesh, sanitize_spec(s, x.shape, mesh))),
-        tree, spec_tree)
-    rep = jax.tree.map(lambda x: P(*([None] * jnp.ndim(x))),
-                       state.server_params)
-    state.server_params = put(state.server_params, rep)
-    state.server_opt = jax.tree.map(
-        lambda x: jax.device_put(x, NamedSharding(mesh, P(*([None] *
-                                                            jnp.ndim(x))))),
-        state.server_opt)
-    state.client_params = put(state.client_params,
-                              client_stacked_specs(state.client_params))
-    copt_specs = client_opt_specs(state.client_params)
-    state.client_opt = {
-        "m": put(state.client_opt["m"], copt_specs["m"]),
-        "v": put(state.client_opt["v"], copt_specs["v"]),
-        "step": jax.device_put(
-            state.client_opt["step"],
-            NamedSharding(mesh, sanitize_spec(
-                copt_specs["step"], state.client_opt["step"].shape, mesh))),
-    }
+    state.server_params = replicate(mesh, state.server_params)
+    state.server_opt = replicate(mesh, state.server_opt)
+    state.client_params, state.client_opt = shard_client_stack(
+        mesh, state.client_params, state.client_opt)
     return state
 
 

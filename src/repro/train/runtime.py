@@ -54,9 +54,11 @@ serve/runtime.py's, and the mirror image of its queue→engine loop):
   (``ema_decay``) maintains the smoothed server net that sampling/serve
   should load (``sampling_server_params``).
 * **Sharding.**  The runtime is mesh-agnostic; pass ``mesh`` to place
-  the round stacks with the cohort specs
-  (sharding/specs.shard_cohort_round — client axis over "clients", like
-  the stacked training state).  launch/collab_dryrun.py's
+  each round's operands: the round stacks with the cohort specs
+  (sharding/specs.shard_cohort_round), the stacked client nets and their
+  AdamW state one client per device (shard_client_stack) — both with
+  the client axis over "clients" — and the server net replicated.
+  launch/collab_dryrun.py's
   ``train_runtime`` entry compiles the identity-keyed cohort round on
   the ("clients", "data") mesh.
 * **Async (staleness-tolerant) aggregation — the round barrier falls.**
@@ -197,12 +199,9 @@ _TRAIN_REPORT_SCHEMA = {
 
 def _key_pack(key) -> Dict[str, Any]:
     """Checkpointable form of a PRNG key (raw uint32 or typed)."""
-    try:
-        data, typed = jax.random.key_data(key), True
-        typed = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
-    except TypeError:
-        data, typed = key, False
-    return {"data": np.asarray(data), "typed": bool(typed)}
+    typed = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
+    return {"data": np.asarray(jax.random.key_data(key)),
+            "typed": bool(typed)}
 
 
 def _key_unpack(packed) -> Any:
@@ -543,7 +542,15 @@ class TrainRuntime:
                                [members[0].opt] * pad)
             xs, ys, mask, uids = plan.xs, plan.ys, plan.mask, plan.uids
             if self.mesh is not None:
-                from repro.sharding.specs import shard_cohort_round
+                # the server net is placed too, before every round: an
+                # unplaced tree (fresh or restored) and the round's own
+                # mesh-placed output would otherwise be two signatures
+                from repro.sharding.specs import (replicate,
+                                                  shard_client_stack,
+                                                  shard_cohort_round)
+                cp, co = shard_client_stack(self.mesh, cp, co)
+                self.server_params, self.server_opt = replicate(
+                    self.mesh, (self.server_params, self.server_opt))
                 xs, ys, mask, uids = shard_cohort_round(self.mesh, xs, ys,
                                                         mask, uids)
             rkey = jax.random.fold_in(

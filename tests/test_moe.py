@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_arch, reduced
+from repro.launch.mesh import make_debug_mesh
 from repro.models.moe import moe_dense, moe_ep, moe_init
 from repro.models.transformer import Runtime
 
@@ -53,7 +54,7 @@ def test_ep_equals_dense_single_shard(key):
     p = moe_init(key, cfg, jnp.float32)
     x = jax.random.normal(key, (2, 16, cfg.d_model))
     y_d, aux_d = moe_dense(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh()
     y_e, aux_e = moe_ep(p, x, cfg, mesh, ("data",))
     np.testing.assert_allclose(np.asarray(y_e), np.asarray(y_d), atol=1e-4,
                                rtol=1e-3)
@@ -65,6 +66,7 @@ _SUBPROCESS = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, jax, jax.numpy as jnp, numpy as np
     from repro.configs.base import get_arch, reduced
+    from repro.launch.mesh import make_debug_mesh
     from repro.models.moe import moe_dense, moe_ep, moe_init
     cfg = dataclasses.replace(reduced(get_arch("dbrx-132b")),
                               capacity_factor=8.0)
@@ -72,7 +74,7 @@ _SUBPROCESS = textwrap.dedent("""
     p = moe_init(key, cfg, jnp.float32)
     x = jax.random.normal(key, (4, 16, cfg.d_model))
     y_d, aux_d = moe_dense(p, x, cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(2, 4)
     y_e, aux_e = jax.jit(
         lambda xx: moe_ep(p, xx, cfg, mesh, ("data",)))(x)
     np.testing.assert_allclose(np.asarray(y_e), np.asarray(y_d),
@@ -97,7 +99,7 @@ def test_capacity_drops_tokens(key):
     cfg = _cfg(capacity_factor=0.1)
     p = moe_init(key, cfg, jnp.float32)
     x = jax.random.normal(key, (2, 32, cfg.d_model))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh()
     y, _ = moe_ep(p, x, cfg, mesh, ("data",))
     y_full, _ = moe_dense(p, x, cfg)
     assert float(jnp.abs(y).mean()) < float(jnp.abs(y_full).mean()) + 1e-6
@@ -111,7 +113,7 @@ def test_ep2d_equals_dense_single_shard(key):
     p = moe_init(key, cfg, jnp.float32)
     x = jax.random.normal(key, (2, 4, cfg.d_model))
     y_d, _ = moe_dense(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh()
     y_e, _ = moe_ep2d(p, x, cfg, mesh, ("data",))
     np.testing.assert_allclose(np.asarray(y_e), np.asarray(y_d), atol=1e-4,
                                rtol=1e-3)
@@ -122,6 +124,7 @@ _SUBPROCESS_2D = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, jax, jax.numpy as jnp, numpy as np
     from repro.configs.base import get_arch, reduced
+    from repro.launch.mesh import make_debug_mesh
     from repro.models.moe import moe_dense, moe_ep2d, moe_init
     cfg = dataclasses.replace(reduced(get_arch("dbrx-132b")),
                               capacity_factor=8.0)
@@ -129,7 +132,7 @@ _SUBPROCESS_2D = textwrap.dedent("""
     p = moe_init(key, cfg, jnp.float32)
     x = jax.random.normal(key, (4, 2, cfg.d_model))
     y_d, _ = moe_dense(p, x, cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(2, 4)
     y_e, _ = jax.jit(lambda xx: moe_ep2d(p, xx, cfg, mesh, ("data",)))(x)
     np.testing.assert_allclose(np.asarray(y_e), np.asarray(y_d),
                                atol=1e-4, rtol=1e-3)

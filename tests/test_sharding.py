@@ -1,5 +1,10 @@
 """Sharding-spec unit tests (the dry-run exercises the full configs; these
 check the rules themselves on one device)."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,7 +112,7 @@ def test_serve_plan_and_inject_specs_on_mesh():
     assert S.inject_specs(plan.inject).x == \
         P(S.CLIENT_AXIS, "data", None, None, None)
     assert S.handoff_spec(1 + len(img)) == P("data", None, None, None)
-    mesh = jax.make_mesh((1,), (S.CLIENT_AXIS,))
+    mesh = S.make_client_mesh(1)
     tables = S.shard_sample_plan(mesh, plan.tables)
     inject = S.shard_inject(mesh, plan.inject)
     entry = jax.device_put(stored, jax.sharding.NamedSharding(
@@ -135,3 +140,107 @@ def test_inference_layout_drops_fsdp():
     infer2 = S.param_specs(shapes2, inference=True)
     assert infer2["mamba"]["x_proj"] == P(None, None, "model")
     assert infer2["mamba"]["out_proj"] == P(None, "model", None)
+
+
+_FOUR_DEVICE_ROUND = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.core.collab import CollabConfig, build_denoiser
+    from repro.sharding.specs import CLIENT_AXIS, make_client_mesh, make_mesh
+    from repro.train import ParticipationConfig, TrainConfig, TrainRuntime
+    key = jax.random.PRNGKey(0)
+    init_one, apply_fn = build_denoiser(key, CollabConfig(
+        image_size=8, n_classes=4))
+    cfg = TrainConfig(T=20, t_cut=5, image_shape=(8, 8, 3), n_classes=4,
+                      batch_size=2, batches_per_round=1,
+                      participation=ParticipationConfig(policy="full"))
+    rts = []
+    for mesh in (make_client_mesh(4), make_mesh((1,), (CLIENT_AXIS,))):
+        rt = TrainRuntime(cfg, init_one, apply_fn, key, mesh=mesh)
+        for c in range(4):
+            x = jax.random.normal(jax.random.fold_in(key, c), (4, 8, 8, 3))
+            rt.register_client(x, np.eye(4, dtype=np.float32)[[c] * 4])
+        rt.run(2)
+        rts.append(rt)
+    four, one = rts
+    assert four.mesh.devices.size == 4 and four.traces == 1
+    def rel(a, b):
+        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+        num = sum(float(np.sum((np.asarray(x, np.float64) - np.asarray(y)) ** 2))
+                  for x, y in zip(la, lb))
+        return (num / sum(float(np.sum(np.asarray(y, np.float64) ** 2))
+                          for y in lb)) ** 0.5
+    gaps = [rel(four.server_params, one.server_params)] + [
+        rel(four.registry.get(u).params, one.registry.get(u).params)
+        for u in one.registry.uids()]
+    print("FOUR_DEVICE_ROUND_OK", max(gaps))
+""")
+
+
+def test_four_device_client_mesh_round_matches_one_device():
+    """The U-Net round with one client per device (4 CPU devices) trains
+    what the one-device round does.  Only the reduction order of the
+    server update differs (~1e-7 relative in float32 over 2 rounds); a
+    misplaced or mis-partitioned client moves its net by a whole update
+    (~1e-2).  This is what caught XLA's CPU partitioner returning wrong
+    values for the vmapped 1x1 skip convolution."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    r = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_ROUND], env=env,
+                       capture_output=True, text=True, timeout=600,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert "FOUR_DEVICE_ROUND_OK" in r.stdout, r.stdout + r.stderr[-4000:]
+    gap = float(r.stdout.split("FOUR_DEVICE_ROUND_OK")[1].split()[0])
+    assert gap < 1e-4, gap
+
+
+# A vmapped 1x1 convolution and the same 1x1 as a matmul, over a clients
+# axis sharded on 4 devices against the unsharded program; prints the
+# worst difference relative to the largest output, per matmul precision.
+_SKIP_CONV = textwrap.dedent("""
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.unet import conv, conv_init
+    from repro.sharding.specs import CLIENT_AXIS, make_mesh
+    mesh = make_mesh((4,), (CLIENT_AXIS,))
+    key = jax.random.PRNGKey(0)
+    ps = jax.vmap(lambda k: conv_init(k, 1, 1, 32, 64, jnp.float32))(
+        jax.random.split(key, 4))
+    x = jax.random.normal(key, (4, 2, 8, 8, 32))
+    put = lambda t: jax.tree.map(lambda a: jax.device_put(
+        a, NamedSharding(mesh, P(CLIENT_AXIS))), t)
+    forms = {"conv": conv, "matmul": lambda p, a: a @ p["w"][0, 0] + p["b"]}
+    for prec in ("default", "highest"):
+        gaps = {}
+        with jax.default_matmul_precision(prec):
+            for name, f in forms.items():
+                ref = np.asarray(jax.jit(jax.vmap(f))(ps, x))
+                out = np.asarray(jax.jit(jax.vmap(f))(put(ps), put(x)))
+                gaps[name] = float(np.abs(out - ref).max() / np.abs(ref).max())
+        print("SKIP_CONV", prec, gaps["conv"], gaps["matmul"])
+""")
+
+
+def test_unet_skip_is_a_matmul_because_sharded_1x1_conv_is_wrong():
+    """Why core/unet.res_block computes its 1x1 skip as a matmul: vmapped
+    over a ``clients`` axis sharded on 4 CPU devices, XLA returns wrong
+    values for the 1x1 ``conv_general_dilated`` (off by more than the
+    output itself) and exact ones for the matmul.  If the conv assertion
+    fails, XLA partitions the convolution correctly and the skip can be
+    ``conv`` again."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    r = subprocess.run([sys.executable, "-c", _SKIP_CONV], env=env,
+                       capture_output=True, text=True, timeout=300,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    rows = [line.split()[1:] for line in r.stdout.splitlines()
+            if line.startswith("SKIP_CONV")]
+    assert [p for p, *_ in rows] == ["default", "highest"], \
+        r.stdout + r.stderr[-4000:]
+    for prec, conv_gap, matmul_gap in rows:
+        assert float(matmul_gap) <= 1e-6, (prec, matmul_gap)
+        assert float(conv_gap) > 0.1, (
+            f"at {prec} precision the sharded 1x1 conv now matches "
+            f"({conv_gap}): the U-Net skip can be conv() again")

@@ -335,6 +335,26 @@ def test_runtime_one_signature_per_tier(key):
         sum(rep["real_samples"] for rep in reps)
 
 
+def test_runtime_on_mesh_compiles_once_and_matches(key):
+    """On a ``clients`` mesh the round compiles once for its tier (round 0
+    sees an unplaced server net, later rounds the mesh-placed output of
+    the last round) and trains exactly what the mesh-less runtime does."""
+    from repro.sharding.specs import make_client_mesh
+    sizes, full = [8, 8, 8, 8], ParticipationConfig(policy="full")
+    plain = make_runtime(key, sizes=sizes, participation=full)
+    meshed = TrainRuntime(tiny_config(participation=full), tiny_init,
+                          tiny_apply, key, mesh=make_client_mesh(4))
+    for i, n in enumerate(sizes):
+        meshed.register_client(*tiny_data(i, n))
+    plain.run(3)
+    reps = meshed.run(3)
+    assert meshed.traces == 1 and reps[-1]["engine_traces"] == 0
+    assert trees_equal(meshed.server_params, plain.server_params)
+    for u in plain.registry.uids():
+        assert trees_equal(meshed.registry.get(u).params,
+                           plain.registry.get(u).params), u
+
+
 def test_runtime_absent_client_is_frozen(key):
     """A client that leaves keeps params/opt bitwise-frozen while away
     and trains again after rejoin."""
