@@ -1,0 +1,10 @@
+"""Device milliseconds a step of the server stage: the time of the
+``jit_server_stage`` runs wholly inside the traced window's read part, over
+the padded steps those waves scanned (the runtime's ``server_scan_steps``
+histogram, matched to the runs in dispatch order)."""
+from bench import stages
+
+
+def read(rec):
+    return stages.step_ms(rec.get("stages"), stages.SERVER,
+                          "server_scan_steps")

@@ -45,9 +45,10 @@ menu, and SLO accounting tracks every deadline-carrying request
 observability pass (obs tentpole): a fully-traced replica
 (JSONL + Perfetto sinks) is bitwise-equal to the untraced run with zero
 extra jit signatures, its JSONL stream round-trips, and its wave spans
-decompose into plan/cache_probe/server_scan/client_scan/straggle_stall
-children.  Outside the smoke, ``--obs-jsonl``/``--trace-out``/
-``--profile-waves`` turn the sinks on for real runs (see repro.obs).
+decompose into plan/cache_probe/enqueue_server/cache_fill/
+enqueue_client/account/straggle_stall children.  Outside the smoke,
+``--obs-jsonl``/``--trace-out``/``--profile-waves`` turn the sinks on for
+real runs (see repro.obs).
 """
 from __future__ import annotations
 
@@ -263,8 +264,8 @@ def smoke(args, queue, sp, cp, apply_fn, sched, key) -> dict:
     # runtime produces bitwise-identical samples, identical cache/call
     # accounting, and ZERO extra jit signatures, while emitting a
     # round-trippable JSONL stream and a Perfetto trace whose wave spans
-    # decompose into plan/cache_probe/server_scan/client_scan/
-    # straggle_stall children
+    # decompose into plan/cache_probe/enqueue_server/cache_fill/
+    # enqueue_client/account/straggle_stall children
     with tempfile.TemporaryDirectory() as td:
         jsonl = os.path.join(td, "serve.jsonl")
         trace = os.path.join(td, "trace.json")
@@ -300,8 +301,8 @@ def smoke(args, queue, sp, cp, apply_fn, sched, key) -> dict:
             by_parent.setdefault(e["args"].get("parent"), set()) \
                 .add(e["name"])
         kids = by_parent.get(waves[0]["args"]["sid"], set())
-        assert {"plan", "server_scan", "client_scan",
-                "straggle_stall"} <= kids, kids
+        assert {"plan", "enqueue_server", "cache_fill", "enqueue_client",
+                "account", "straggle_stall"} <= kids, kids
         assert any(e["name"] == "cache_probe" for e in events)
         # every ticket links to its wave's span id
         wave_sids = {w["args"]["sid"] for w in waves}

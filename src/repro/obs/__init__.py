@@ -9,11 +9,14 @@ through ONE subsystem:
   delta-vs-gauge report taxonomy enforced in code (and the shared
   ``RecompileGuard`` jit trace counter both runtimes assert on).
 * ``obs.trace`` — nestable spans with injected clocks.  Serve waves
-  decompose into straggle_stall / plan / cache_probe / server_scan /
-  client_scan / retire children; train rounds into cohort_sample /
-  plan / round_dispatch / barrier_stall / fedavg / checkpoint.  Wave
-  and round spans close at OBSERVED completion (the PR-7 ready-probe
-  gauge) and are attributed to their retire frame.
+  decompose into straggle_stall / plan / cache_probe / enqueue_server /
+  cache_fill / enqueue_client / account / retire children; train rounds
+  into cohort_sample / plan / round_dispatch / barrier_stall / fedavg /
+  checkpoint.  Wave and round spans close at OBSERVED completion (the
+  PR-7 ready-probe gauge) and are attributed to their retire frame.
+  Every synchronous span is also a ``jax.profiler.TraceAnnotation``
+  named ``<runtime>/<span>`` (``serve/account``), so a profiler trace
+  puts the host's work between device operations on the device's clock.
 * ``obs.export`` — JSONL event stream, Perfetto/Chrome trace export,
   and an opt-in ``jax.profiler`` session.
 
@@ -21,10 +24,10 @@ THE OBS CONTRACT (pinned by tests/test_obs.py and both CLI smokes):
 
 1. **Disabled is the default and structurally inert.**  A runtime built
    without an ObsConfig holds the NullTracer singleton — no Span objects
-   on the hot path, no sink IO, and reports/samples bitwise-identical
-   to the pre-obs runtime.  (The metrics registry itself always runs:
-   it IS the report mechanism, and its cost is integer adds the old
-   hand-maintained dicts paid anyway.)
+   or profiler annotations on the hot path, no sink IO, and
+   reports/samples bitwise-identical to the pre-obs runtime.  (The
+   metrics registry itself always runs: it IS the report mechanism, and
+   its cost is integer adds the old hand-maintained dicts paid anyway.)
 2. **Enabled never perturbs outputs.**  Tracing adds host-side clock
    reads and buffer appends only: samples/params stay bitwise-identical
    to the disabled run and the engines compile ZERO new jit signatures
@@ -54,9 +57,11 @@ Workflow::
 
     # then load /tmp/serve_trace.json in https://ui.perfetto.dev (or
     # chrome://tracing): each wave is a lane; its plan/cache_probe/
-    # server_scan/client_scan/straggle_stall children nest inside it.
+    # enqueue_server/cache_fill/enqueue_client/account/straggle_stall
+    # children nest inside it.
 
-    # device-level truth for the first 8 waves (TensorBoard-loadable):
+    # device-level truth for the first 8 waves (TensorBoard-loadable;
+    # the serve/... and train/... annotations sit beside the device ops):
     ... --profile-waves 8 --profile-dir /tmp/jaxprof
 """
 from __future__ import annotations
@@ -93,17 +98,20 @@ class Telemetry:
 
     The registry is ALWAYS live (reports derive from it); the tracer and
     sinks exist only when the config is active — otherwise the singleton
-    NullTracer stands in and every sink hook is a no-op."""
+    NullTracer stands in and every sink hook is a no-op.  ``runtime``
+    prefixes the tracer's profiler annotations (``serve/plan``)."""
 
     def __init__(self, config: Optional[ObsConfig] = None,
                  clock=time.perf_counter,
-                 registry: Optional[MetricsRegistry] = None):
+                 registry: Optional[MetricsRegistry] = None,
+                 runtime: str = "obs"):
         self.config = config or ObsConfig()
         self.clock = clock
         self.registry = registry if registry is not None \
             else MetricsRegistry()
         self.enabled = self.config.active
-        self.tracer = Tracer(clock) if self.enabled else NULL_TRACER
+        self.tracer = Tracer(clock, runtime) if self.enabled \
+            else NULL_TRACER
         self._jsonl = (JsonlSink(self.config.jsonl_path, clock)
                        if self.config.jsonl_path else None)
         self._spans = []          # retained for the chrome trace export

@@ -37,6 +37,7 @@ frame deltas (zero in steady state).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -208,7 +209,10 @@ class RecompileGuard:
     jit traces a NEW signature, so the counter counts compiles, and its
     per-frame delta (via the registry snapshot) is the recompile guard
     the CI smokes assert on.  One guard may wrap several stages (the
-    split serve engine): the count is the total across them."""
+    split serve engine): the count is the total across them.  The
+    wrapper keeps ``fn``'s name, so each program lowers under its own
+    (``jit_server_stage``, ``jit_round_fn``) and a profiler trace tells
+    them apart."""
 
     def __init__(self, counter: Counter):
         self._counter = counter
@@ -218,6 +222,7 @@ class RecompileGuard:
         return self._counter.value
 
     def wrap(self, fn: Callable) -> Callable:
+        @functools.wraps(fn)
         def traced(*args, **kwargs):
             self._counter.inc()
             return fn(*args, **kwargs)

@@ -22,11 +22,19 @@ addressed-randomness discipline: tests drive a fake clock and assert
 exact span math, and a runtime's tracer shares the runtime's clock so
 spans and ticket timestamps are directly comparable.
 
+Synchronous spans are also put on the profiler's clock: each opens a
+``jax.profiler.TraceAnnotation`` named ``<runtime>/<name>`` (``serve/plan``,
+``train/fedavg``), so a profiler trace shows what the host was doing
+between device operations.  Asynchronous spans (``start``/``end``) do not:
+overlapping waves describe no host activity of their own.  An annotation
+is a no-op while no profiler session is running.
+
 Disabled tracing is STRUCTURALLY INERT: the runtimes hold the module
 singleton ``NULL_TRACER``, whose ``span``/``start``/``end`` allocate no
-Span objects and return shared constants — the hot path pays one
-attribute lookup and a no-op call, and the obs contract (reports and
-samples bitwise-identical to pre-obs behavior) holds by construction.
+Span objects and no annotations and return shared constants — the hot
+path pays one attribute lookup and a no-op call, and the obs contract
+(reports and samples bitwise-identical to pre-obs behavior) holds by
+construction.
 
 Completed spans buffer until a sink drains them (obs/export.py); the
 buffer is bounded only by frame cadence, which is fine at wave/round
@@ -37,6 +45,8 @@ from __future__ import annotations
 
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -67,27 +77,34 @@ class Span:
 
 class _SpanContext:
     """Context manager for synchronous spans (allocated only when the
-    tracer is enabled)."""
-    __slots__ = ("_tracer", "span")
+    tracer is enabled); the span's profiler annotation opens and closes
+    with it."""
+    __slots__ = ("_tracer", "span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
+        self._annotation = TraceAnnotation(tracer.prefix + span.name)
 
     def __enter__(self) -> Span:
+        self._annotation.__enter__()
         self._tracer._stack.append(self.span)
         return self.span
 
     def __exit__(self, *exc) -> None:
         self._tracer._stack.pop()
         self._tracer.end(self.span)
+        self._annotation.__exit__(*exc)
 
 
 class Tracer:
-    """Span factory + completion buffer, driven by an injected clock."""
+    """Span factory + completion buffer, driven by an injected clock.
+    ``runtime`` names the profiler annotations of synchronous spans
+    (``<runtime>/<span>``)."""
 
-    def __init__(self, clock=time.perf_counter):
+    def __init__(self, clock=time.perf_counter, runtime: str = "obs"):
         self.clock = clock
+        self.prefix = runtime + "/"
         self.frame = 0               # current report-frame index
         self.enabled = True
         self._next_sid = 0

@@ -119,16 +119,24 @@ core/collab.py's vectorized-round notes):
   key is classified in the registry (``_SERVE_REPORT_SCHEMA``) and
   tests/test_obs.py fails on an unclassified or shape-drifting key.
   With an active ObsConfig, each wave opens a span decomposed into
-  straggle_stall / plan / cache_probe / server_scan / client_scan
-  children; the wave span closes at OBSERVED completion (the same
-  ready-probe gauge as ticket latency, carrying ``device_wait_s``) and
-  is attributed to the report frame it RETIRES in, exactly like the
-  ticket percentiles.  The obs contract: disabled (default) is
-  structurally inert — NullTracer singleton, zero span allocations, no
-  sink IO, reports and samples bitwise-identical to the pre-obs
-  runtime; enabled never perturbs outputs — samples bitwise-identical
-  to the disabled run with ZERO new jit signatures (pinned by
-  tests/test_obs.py and the collab_serve --smoke obs pass).
+  straggle_stall / plan / cache_probe / enqueue_server / cache_fill /
+  enqueue_client / account children (the two enqueue spans time the
+  asynchronous dispatch of each engine stage, not the stage's device
+  time; ``account`` holds the host until the device has both stages'
+  tables); each is also a ``serve/<name>`` profiler annotation, and the
+  stages lower as ``jit_server_stage`` and ``jit_client_stage``, so a
+  profiler trace gives per-stage device time and what the host did in
+  each gap.  The registry histograms ``server_scan_steps`` and
+  ``client_scan_steps`` hold each dispatched wave's padded step count
+  (observed whether or not tracing is on).  The wave span closes at
+  OBSERVED completion (the same ready-probe gauge as ticket latency,
+  carrying ``device_wait_s``) and is attributed to the report frame it
+  RETIRES in, exactly like the ticket percentiles.  The obs contract:
+  disabled (default) is structurally inert — NullTracer singleton, zero
+  span allocations, no sink IO, reports and samples bitwise-identical
+  to the pre-obs runtime; enabled never perturbs outputs — samples
+  bitwise-identical to the disabled run with ZERO new jit signatures
+  (pinned by tests/test_obs.py and the collab_serve --smoke obs pass).
 
 Reproducibility contract: the serve path is SYNCHRONOUS and bitwise —
 every mode of this runtime (pipelined or sequential, any scheduler
@@ -295,7 +303,8 @@ class ServeRuntime:
         # -- observability: registry (always live — it IS the report
         # mechanism), tracer + sinks (only when an ObsConfig is active)
         self._obs = obs if isinstance(obs, Telemetry) \
-            else Telemetry(obs if isinstance(obs, ObsConfig) else None)
+            else Telemetry(obs if isinstance(obs, ObsConfig) else None,
+                           runtime="serve")
         self._clock = self._obs.clock
         self.registry = self._obs.registry
         self.registry.declare_all(_SERVE_REPORT_SCHEMA)
@@ -307,6 +316,8 @@ class ServeRuntime:
             "server_calls_saved_by_cache", "requests_from_cache")}
         self._hist_latency = self.registry.histogram("latency_s")
         self._hist_wait = self.registry.histogram("admit_wait_s")
+        self._hist_server_steps = self.registry.histogram("server_scan_steps")
+        self._hist_client_steps = self.registry.histogram("client_scan_steps")
         self.cache = PrefixCache(config.cache_max_bytes,
                                  config.cache_max_entries) \
             if config.cache else None
@@ -582,13 +593,12 @@ class ServeRuntime:
         window.  With obs enabled the wave span opens here and closes at
         OBSERVED completion in ``_retire``; its children decompose the
         host-side work (straggle_stall / plan / cache_probe /
-        server_scan / client_scan)."""
+        enqueue_server / cache_fill / enqueue_client / account)."""
         cfg = self.config
         tr = self._obs.tracer
         wspan = tr.start("wave", bucket=label,
                          wave=self._c["waves"].value,
-                         n_requests=len(tickets),
-                         rids=[t.rid for t in tickets])
+                         n_requests=len(tickets))
         self._obs.step()
         if cfg.straggle_s > 0.0:
             with tr.span("straggle_stall", parent=wspan,
@@ -625,10 +635,12 @@ class ServeRuntime:
                 n_requests=self.scheduler.max_wave,
                 n_inject=self.scheduler.inject_tier(plan.n_hits)
                 if plan.inject is not None else None)
-        with tr.span("server_scan", parent=wspan, n_groups=plan.n_groups):
+        with tr.span("enqueue_server", parent=wspan,
+                     n_groups=plan.n_groups):
             handoff = self._server_stage(self.server_params, self._key,
                                          padded.tables)
-            if use_cache:
+        if use_cache:
+            with tr.span("cache_fill", parent=wspan):
                 for g in range(plan.n_groups):
                     # zero-step (ICM) prefixes are uncacheable by design;
                     # don't churn the rejected counter every wave.  The
@@ -641,23 +653,26 @@ class ServeRuntime:
                         self.cache.insert(
                             self._cache_key(plan.group_keys[g]),
                             handoff[g], plan.group_steps[g])
-        with tr.span("client_scan", parent=wspan, n_hits=plan.n_hits):
+        with tr.span("enqueue_client", parent=wspan, n_hits=plan.n_hits):
             out = self._client_stage(self.client_params, self._key,
                                      padded.tables, handoff, padded.inject)
         self._inflight.append((out, tuple(tickets), wspan))
+        self._hist_server_steps.observe(padded.tables.group_t.shape[1])
+        self._hist_client_steps.observe(padded.tables.client_t.shape[1])
         c = self._c
-        for k_, v in call_accounting(padded).items():
-            c[k_].inc(v)
-        c["server_calls_saved_by_dedup"].inc(plan.server_steps_saved)
-        c["server_calls_saved_by_cache"].inc(
-            plan.server_steps_saved_by_cache)
-        rg = np.asarray(plan.tables.request_group)
-        c["requests_from_cache"].inc(int((rg >= plan.n_groups).sum()))
-        self._frame.sigs.setdefault(label, set()).add(
-            plan_signature(padded))
-        c["waves"].inc()
-        c["n_samples"].inc(
-            sum(int(t.request.y.shape[0]) for t in tickets))
+        with tr.span("account", parent=wspan):
+            for k_, v in call_accounting(padded).items():
+                c[k_].inc(v)
+            c["server_calls_saved_by_dedup"].inc(plan.server_steps_saved)
+            c["server_calls_saved_by_cache"].inc(
+                plan.server_steps_saved_by_cache)
+            rg = np.asarray(plan.tables.request_group)
+            c["requests_from_cache"].inc(int((rg >= plan.n_groups).sum()))
+            self._frame.sigs.setdefault(label, set()).add(
+                plan_signature(padded))
+            c["waves"].inc()
+            c["n_samples"].inc(
+                sum(int(t.request.y.shape[0]) for t in tickets))
         td = self._clock()
         for t in tickets:
             t.t_dispatch = td

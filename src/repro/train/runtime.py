@@ -124,7 +124,9 @@ serve/runtime.py's, and the mirror image of its queue→engine loop):
   active ObsConfig each round is one report FRAME and one "round" span
   decomposed into cohort_sample / plan / round_dispatch /
   barrier_stall / fedavg children (plus a "checkpoint" span in
-  ``run``), streamed to the JSONL/Perfetto sinks.  The obs contract is
+  ``run``), streamed to the JSONL/Perfetto sinks; each child is also a
+  ``train/<name>`` profiler annotation, and the round program lowers as
+  ``jit_round_fn``.  The obs contract is
   the serve runtime's exactly: disabled (default) is structurally
   inert — NullTracer singleton, zero span allocations, no sink IO,
   reports and params bitwise-identical to the pre-obs runtime; enabled
@@ -261,7 +263,8 @@ class TrainRuntime:
         # runtime's live state is exposed through callback gauges so a
         # JSONL frame always carries the current cursor/roster/ledger.
         self._obs = obs if isinstance(obs, Telemetry) \
-            else Telemetry(obs if isinstance(obs, ObsConfig) else None)
+            else Telemetry(obs if isinstance(obs, ObsConfig) else None,
+                           runtime="train")
         self._clock = self._obs.clock
         self.metrics = self._obs.registry
         self.metrics.declare_all(_TRAIN_REPORT_SCHEMA)

@@ -382,3 +382,92 @@ def test_runtimes_default_to_inert_obs():
     for rt in (_serve_runtime(), _train_runtime()):
         assert rt.obs.enabled is False
         assert rt.obs.tracer is NULL_TRACER
+
+
+# ---------------------------------------------------------------------------
+# Stable program names and spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_recompile_guard_keeps_the_wrapped_name():
+    reg = MetricsRegistry()
+    guard = RecompileGuard(reg.counter("engine_traces"))
+
+    def server_stage(x):
+        return x + 1.0
+
+    fn = jax.jit(guard.wrap(server_stage))
+    text = fn.lower(jnp.ones((3,))).as_text()
+    assert text.startswith("module @jit_server_stage"), text[:80]
+    assert guard.count == 1
+    fn(jnp.ones((3,)))
+    fn(jnp.ones((3,)) + 1)
+    assert guard.count == 1                  # still one trace a signature
+    fn(jnp.ones((4,)))
+    assert guard.count == 2
+
+
+def test_runtime_programs_keep_their_names():
+    serve, train = _serve_runtime(), _train_runtime()
+    assert serve._server_stage.__name__ == "server_stage"
+    assert serve._client_stage.__name__ == "client_stage"
+    assert train._engine.__name__ == "round_fn"
+
+
+def _host_event_names(body):
+    """Names of the host events a profiler session records around
+    ``body()`` (host tracer level 1, no Python tracer)."""
+    from jax._src.lib import _profiler
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.devices()
+    session = _profiler.ProfilerSession(opts)
+    try:
+        body()
+    finally:
+        data = session.stop_and_get_profile_data()
+    return [e.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_tracer_spans_are_profiler_annotations():
+    tr = Tracer(FakeClock(), runtime="train")
+
+    def body():
+        w = tr.start("round")
+        with tr.span("fedavg", parent=w):
+            with tr.span("checkpoint"):
+                pass
+        tr.end(w)
+
+    names = _host_event_names(body)
+    assert {"train/fedavg", "train/checkpoint"} <= set(names)
+    # asynchronous spans describe no host activity: not annotated
+    assert "train/round" not in names
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_serve_annotations_only_with_obs_on(enabled):
+    from repro.core.sample_plan import SampleRequest
+    from repro.core.schedules import DiffusionSchedule
+    from repro.serve import ServeConfig, ServeRuntime
+    sp = {"a": jnp.float32(0.2), "b": jnp.float32(0.0)}
+    cp = {"a": jnp.linspace(0.1, 0.5, 3), "b": jnp.zeros((3,))}
+    rt = ServeRuntime(
+        ServeConfig(T=16, image_shape=(4, 4, 3), max_wave=4),
+        sp, cp, lambda p, x, t, y: x * p["a"] + p["b"],
+        DiffusionSchedule.linear(16), jax.random.PRNGKey(0),
+        obs=ObsConfig(enabled=enabled))
+    y = np.eye(4, dtype=np.float32)[:2]
+    queue = [SampleRequest(client=c, t_cut=4, y=y) for c in range(3)]
+    names = [n for n in _host_event_names(lambda: rt.process(queue))
+             if n.startswith("serve/")]
+    if enabled:
+        assert {"serve/plan", "serve/cache_probe", "serve/enqueue_server",
+                "serve/cache_fill", "serve/enqueue_client", "serve/account",
+                "serve/retire"} <= set(names), names
+        assert "serve/wave" not in names
+    else:
+        assert names == []

@@ -698,9 +698,61 @@ def test_finish_report_with_waves_in_flight_and_span_attribution():
     # the host-side children of wave B closed inside frame 1; only the
     # retire probe crossed into frame 2 with the wave span itself
     kids = {s.name: s for s in spans if s.parent == waves[1].sid}
-    assert {"plan", "server_scan", "client_scan",
+    assert {"plan", "enqueue_server", "enqueue_client",
             "retire"} <= set(kids)
     assert kids["plan"].frame == 1
-    assert kids["client_scan"].frame == 1
+    assert kids["enqueue_client"].frame == 1
     assert kids["retire"].frame == 2
     assert waves[1].attrs["device_wait_s"] >= 0.0
+
+
+def test_scan_step_histograms_hold_each_wave_padded_widths():
+    """Once per dispatched wave, ``server_scan_steps``/``client_scan_steps``
+    observe the step widths of the tables the engine stages were handed —
+    padded widths, so a wave whose prefixes all hit the cache reads 0."""
+    rt = _rt(cache=True)
+    handed = {"server": [], "client": []}
+    server, client = rt._server_stage, rt._client_stage
+
+    def spy_server(params, key, tables):
+        handed["server"].append(tables.group_t.shape[1])
+        return server(params, key, tables)
+
+    def spy_client(params, key, tables, handoff, inject):
+        handed["client"].append(tables.client_t.shape[1])
+        return client(params, key, tables, handoff, inject)
+
+    rt._server_stage, rt._client_stage = spy_server, spy_client
+    snap = rt.registry.snapshot()
+    _, cold = rt.process(_queue())
+    _, warm = rt.process(_queue())
+    server_steps = rt.registry.window("server_scan_steps", snap).tolist()
+    client_steps = rt.registry.window("client_scan_steps", snap).tolist()
+    assert server_steps == handed["server"]
+    assert client_steps == handed["client"]
+    assert len(server_steps) == cold["waves"] + warm["waves"]
+    assert 0 in server_steps and max(server_steps) > 0   # warm hits
+    assert set(client_steps) == {4, 8}                   # the two cuts
+
+
+@pytest.mark.parametrize("cache", [True, False])
+def test_dispatch_spans_sit_under_their_wave_in_order(cache):
+    from repro.obs import ObsConfig
+    rt = ServeRuntime(ServeConfig(T=T, image_shape=IMG, max_wave=4,
+                                  cache=cache),
+                      SP, CP, apply_fn, SCHED, jax.random.PRNGKey(0),
+                      obs=ObsConfig(enabled=True))
+    rt.process(_queue())
+    spans = rt.obs.spans()
+    waves = [s for s in spans if s.name == "wave"]
+    assert waves
+    expected = ["enqueue_server"] + (["cache_fill"] if cache else []) + \
+        ["enqueue_client", "account"]
+    for w in waves:
+        assert "rids" not in w.attrs
+        kids = sorted((s for s in spans if s.parent == w.sid
+                       and s.name in ("enqueue_server", "cache_fill",
+                                      "enqueue_client", "account")),
+                      key=lambda s: s.t0)
+        assert [s.name for s in kids] == expected
+        assert all(w.t0 <= s.t0 <= s.t1 <= w.t1 for s in kids)
